@@ -32,6 +32,7 @@ from benchmarks import common
 from repro.core import endorser, engine, types, unmarshal
 from repro.kernels.hash_table import ops as ht_ops
 from repro.launch import fabric_step as fs
+from repro.launch.mesh import make_mesh
 
 
 def _round_inputs(dims: types.FabricDims, n: int, seed: int = 0):
@@ -67,7 +68,7 @@ def run(n_buckets: int, slots: int, b_round: int, iters: int,
     for m in _shard_counts(max_m):
         if b_round % m or n_buckets % m:
             continue
-        mesh = jax.make_mesh((1, m), ("data", "model"))
+        mesh = make_mesh((1, m))
         wire, ids = _round_inputs(dims, b_round)
         for label, cfg in (
             ("shard", fs.FASTFABRIC_SHARDED_STEP),
@@ -86,7 +87,7 @@ def run(n_buckets: int, slots: int, b_round: int, iters: int,
 
     if check_equivalence:
         # Acceptance: byte-identical validity bits and ledger/log heads.
-        mesh = jax.make_mesh((1, max_m), ("data", "model"))
+        mesh = make_mesh((1, max_m))
         wire, ids = _round_inputs(dims, b_round, seed=1)
         outs = {}
         for label, cfg in (("shard", fs.FASTFABRIC_SHARDED_STEP),

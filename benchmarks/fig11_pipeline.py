@@ -48,6 +48,7 @@ from repro.analysis import contracts
 from repro.core import endorser, engine, types, unmarshal
 from repro.launch import fabric_step as fs
 from repro.launch import hlo_cost
+from repro.launch.mesh import make_mesh
 
 # The fused-commit budget comes from the committed program contracts
 # (src/repro/analysis/contracts.json) — the same clause the analysis
@@ -339,7 +340,7 @@ def run(depths: list[int], b_round: int, n_buckets: int, iters: int,
     m = 1 << (n_dev.bit_length() - 1)  # largest power of two <= n_dev
     while b_round % m or n_buckets % m or ovf_buckets % m:
         m //= 2
-    mesh = jax.make_mesh((1, m), ("data", "model"))
+    mesh = make_mesh((1, m))
     common.row("fig11", "mesh", model_ranks=m, b_round=b_round)
 
     for label, cfg in (("repl", fs.FASTFABRIC_STEP),

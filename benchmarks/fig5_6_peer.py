@@ -16,7 +16,6 @@ import numpy as np
 
 from benchmarks import common
 from repro.core import committer, types
-from repro.launch import hlo_cost
 
 DIMS = types.PAPER_DIMS
 BS = 100
@@ -51,7 +50,7 @@ def _compiled_flops(pcfg, wire) -> float:
         low = jax.jit(
             lambda s, w: committer.commit_block_fused(s, w, DIMS, pcfg)
         ).lower(state, wire)
-        total += hlo_cost.cost_dict(low.compile()).get("flops", 0.0)
+        total += low.compile().cost_analysis().get("flops", 0.0)
     else:
         for lowered in (
             jax.jit(lambda w: committer.stage_syntax(w, DIMS)).lower(wire),
@@ -62,8 +61,7 @@ def _compiled_flops(pcfg, wire) -> float:
                 pcfg.journal)
             ).lower(state, wire, ok, ok),
         ):
-            total += hlo_cost.cost_dict(
-                lowered.compile()).get("flops", 0.0)
+            total += lowered.compile().cost_analysis().get("flops", 0.0)
     return total
 
 

@@ -1,12 +1,13 @@
 """Roofline analysis from the dry-run artifacts (deliverable g).
 
 Reads experiments/dryrun/*.json (launch/dryrun.py) and derives the three
-roofline terms per (arch x shape x mesh) against TPU v5e constants:
+roofline terms per (arch x shape x mesh) against the peaks of the chip each
+record was compiled for (``PEAKS``, keyed by ``device_kind``):
 
-  compute_s    = HLO_FLOPs_global / (chips * 197 TFLOP/s)
-               = per-device HLO flops / 197e12      (SPMD: HLO is per-chip)
-  memory_s     = per-device HLO bytes / 819 GB/s
-  collective_s = per-device wire bytes / 50 GB/s
+  compute_s    = HLO_FLOPs_global / (chips * peak FLOP/s)
+               = per-device HLO flops / peak      (SPMD: HLO is per-chip)
+  memory_s     = per-device HLO bytes / HBM bytes/s
+  collective_s = per-device wire bytes / ICI bytes/s
 
 FLOPs/bytes/wire come from the trip-count-corrected analyzer
 (repro/launch/hlo_cost.py): XLA's own ``cost_analysis()`` counts while-loop
@@ -29,9 +30,21 @@ import glob
 import json
 import os
 
-PEAK = 197e12  # bf16 FLOP/s per chip
-HBM = 819e9  # B/s per chip
-LINK = 50e9  # B/s per chip ICI
+# Per-chip peaks by ``jax.Device.device_kind``. TPU v5e: Google Cloud
+# documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM; 1,600 Gbit/s
+# interconnect over 4 links, so 50 GB/s per link.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm": 819e9, "link": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The peak table row of ``device_kind``; an unknown chip is an error
+    (no default: a roofline against the wrong chip is a wrong number)."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no roofline peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 HERE = os.path.dirname(__file__)
 DRYRUN = os.path.join(HERE, "..", "experiments", "dryrun")
@@ -54,14 +67,15 @@ def model_flops(rec: dict) -> float:
 
 def analyse(rec: dict) -> dict:
     dev = rec["n_devices"]
+    pk = peaks(rec["device_kind"])
     hc = rec.get("hlo_cost") or {}
     fl = hc.get("flops", rec["cost"]["flops"])  # per-device
     by = hc.get("bytes", rec["cost"]["bytes_accessed"])
     wire = hc.get("collective_wire_bytes",
                   rec["collectives"]["total_wire_bytes"])
-    compute_s = fl / PEAK
-    memory_s = by / HBM
-    coll_s = wire / LINK
+    compute_s = fl / pk["flops"]
+    memory_s = by / pk["hbm"]
+    coll_s = wire / pk["link"]
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": coll_s}
     dom = max(terms, key=terms.get)
@@ -72,9 +86,9 @@ def analyse(rec: dict) -> dict:
     if rec.get("step") == "decode":
         # Decode is traffic-limited: weights + caches stream once.
         arg_bytes = rec.get("memory", {}).get("argument_bytes", 0)
-        ideal_s = arg_bytes / HBM
+        ideal_s = arg_bytes / pk["hbm"]
     else:
-        ideal_s = (mf / dev) / PEAK
+        ideal_s = (mf / dev) / pk["flops"]
     frac = min(1.0, ideal_s / bound_s) if (ideal_s and bound_s) else 0.0
 
     return {

@@ -15,6 +15,7 @@ import argparse
 import time
 
 from benchmarks import common
+from repro.launch import compile_cache
 
 ALL = ("fig3", "fig4", "fig5_6", "fig7", "fig8", "fig9", "fig10", "fig11",
        "fig12", "table1", "roofline")
@@ -33,6 +34,7 @@ def main() -> None:
                     help="dump fig11's obs trace + metrics snapshot here "
                          "(trace.jsonl / trace_chrome.json / metrics.json)")
     args = ap.parse_args()
+    compile_cache.enable()
     which = args.only.split(",") if args.only else list(ALL)
 
     t0 = time.time()
@@ -96,10 +98,7 @@ def main() -> None:
     if "roofline" in which:
         from benchmarks import roofline
         print("== Roofline (from dry-run artifacts) ==")
-        try:
-            roofline.run()
-        except Exception as e:  # dry-run artifacts absent
-            print(f"  (skipped: {e})")
+        roofline.run()
 
     print(f"\n== CSV ({time.time() - t0:.0f}s total) ==")
     common.print_csv()

@@ -33,6 +33,7 @@ from benchmarks import common
 from repro.core import endorser, engine, types, unmarshal
 from repro.launch import fabric_step as fs
 from repro.pipeline import engine_bridge
+from repro.launch.mesh import make_mesh
 
 ROUND = 1_000
 N_ROUNDS = 3
@@ -112,7 +113,7 @@ def run_multichannel(quick: bool = False) -> dict:
     n = 64 if quick else 256
     n_windows = 5 if quick else 8
     nb = 512 if quick else 1 << 11
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = make_mesh((data, model))
     cfg = fs.FabricStepConfig(shard_state=model > 1, pipeline_depth=depth)
     streams = [_windows(n_windows, depth, n, seed=7 * (c + 1))
                for c in range(N_CHANNELS)]

@@ -11,9 +11,11 @@ import numpy as np
 
 from repro.core import engine
 from repro.core import world_state as ws
+from repro.launch import compile_cache
 
 
 def main() -> None:
+    compile_cache.enable()
     print("=== FastFabric on JAX: quickstart ===\n")
     digests = {}
     for name, cfg in (("fabric-1.2 (baseline)", engine.FABRIC_V12),

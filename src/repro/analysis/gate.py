@@ -34,6 +34,7 @@ import jax
 from repro.analysis import checks, contracts, lint, registry
 from repro.analysis.retrace import RetraceAuditor
 from repro.core import types
+from repro.launch import mesh as mesh_lib
 
 
 def make_mesh():
@@ -42,7 +43,7 @@ def make_mesh():
     count valid."""
     n = len(jax.devices())
     m = 1 << (n.bit_length() - 1)
-    return jax.make_mesh((1, m), ("data", "model"))
+    return mesh_lib.make_mesh((1, m))
 
 
 def build_context(mesh=None) -> registry.BuildContext:

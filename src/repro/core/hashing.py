@@ -10,6 +10,8 @@ All functions are shape-polymorphic and jit/vmap/pallas friendly.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -59,7 +61,16 @@ def hash_words(words, seed=SEED_A, axis=-1):
     Order-dependent: uses a multiply-accumulate chain so permutations hash
     differently. Implemented as a vectorized polynomial in u32 (wrapping
     arithmetic): h = ((h * P) + w) mixed at the end.
+
+    One jitted program: a caller outside jit (the host-side chain checks,
+    replay, the endorser replica's unmarshal) pays one dispatch, not five
+    per word (736 words for a 2.9 KB transaction).
     """
+    return _hash_words(words, jnp.asarray(seed, U32), axis)
+
+
+@functools.partial(jax.jit, static_argnames=("axis",))
+def _hash_words(words, seed, axis):
     words = words.astype(U32)
     words = jnp.moveaxis(words, axis, 0)
     h = jnp.full(words.shape[1:], jnp.uint32(seed), dtype=U32)

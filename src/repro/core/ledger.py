@@ -14,6 +14,7 @@ durability argument that lets P-I drop the database (§III-E).
 
 from __future__ import annotations
 
+import functools
 import os
 import queue
 import threading
@@ -100,6 +101,21 @@ class StoredBlock(NamedTuple):
     block_hash: np.ndarray
     wire: np.ndarray
     valid: np.ndarray
+
+
+@jax.jit
+def _link_hash(prev, block_no, wire, valid):
+    """Chain hash of one stored block (``verify_chain``), one program."""
+    return append_hash(prev, block_no, block_body_digest(wire, valid))
+
+
+@functools.partial(jax.jit, static_argnames=("dims",))
+def _replay_block(st, wire, valid, dims):
+    """Apply one stored block's valid writes (``replay_state``)."""
+    dec = unmarshal.unmarshal(wire, dims)
+    return world_state.commit_vectorized(
+        st, dec.txb.write_keys, dec.txb.write_vals, valid
+    ).state
 
 
 class BlockStore:
@@ -324,11 +340,9 @@ class BlockStore:
         for sb in self.chains.get(channel, ()):
             if not np.array_equal(sb.prev_hash, prev):
                 return False
-            digest = block_body_digest(
-                jnp.asarray(sb.wire), jnp.asarray(sb.valid)
-            )
-            expect = append_hash(
-                jnp.asarray(prev), jnp.uint32(sb.block_no), digest
+            expect = _link_hash(
+                jnp.asarray(prev), jnp.uint32(sb.block_no),
+                jnp.asarray(sb.wire), jnp.asarray(sb.valid),
             )
             if not np.array_equal(np.asarray(expect), sb.block_hash):
                 return False
@@ -368,13 +382,8 @@ class BlockStore:
 
         for sb in self.chains.get(channel, ()):
             st = cross(st, sb.block_no - 1)
-            dec = unmarshal.unmarshal(jnp.asarray(sb.wire), dims)
-            st = world_state.commit_vectorized(
-                st,
-                dec.txb.write_keys,
-                dec.txb.write_vals,
-                jnp.asarray(sb.valid),
-            ).state
+            st = _replay_block(st, jnp.asarray(sb.wire),
+                               jnp.asarray(sb.valid), dims)
             st = cross(st, sb.block_no)
         for boundary in sorted(resize_at):
             st = cross(st, boundary)

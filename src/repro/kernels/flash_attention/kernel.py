@@ -74,7 +74,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *, causal: bool,
     static_argnames=("causal", "q_block", "kv_block", "interpret"),
 )
 def flash_attention(q, k, v, *, causal: bool = True, q_block: int = 256,
-                    kv_block: int = 256, interpret: bool = True):
+                    kv_block: int = 256, interpret: bool = False):
     """q (B,S,H,D), k/v (B,Skv,Hkv,D) -> (B,S,H,D). GQA by head grouping."""
     b, sq, h, d = q.shape
     skv, hkv = k.shape[1], k.shape[2]

@@ -1,29 +1,23 @@
-"""Dispatch for flash attention: Pallas on TPU, XLA paths elsewhere."""
+"""Dispatch for flash attention: the Pallas kernel when the caller asks
+for it (``interpret`` only without a TPU), else the XLA paths."""
 
 from __future__ import annotations
-
-import jax
 
 from repro.kernels.flash_attention import kernel, ref
 from repro.models import layers
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def attention(q, k, v, *, causal: bool = True,
-              use_pallas: bool | None = None,
-              q_block: int = 256, kv_block: int = 256):
-    """Self-attention core. Pallas flash kernel on TPU; the exact-causal
-    chunked-scan XLA formulation (models/layers.attn_chunked) on other
-    backends for long sequences; naive scores for short ones."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
+def attention(q, k, v, *, causal: bool = True, use_pallas: bool = False,
+              interpret: bool = False, q_block: int = 256,
+              kv_block: int = 256):
+    """Self-attention core. The Pallas flash kernel with ``use_pallas``;
+    otherwise the exact-causal chunked-scan XLA formulation
+    (models/layers.attn_chunked) for long sequences and naive scores for
+    short ones."""
     if use_pallas:
         return kernel.flash_attention(
             q, k, v, causal=causal, q_block=q_block, kv_block=kv_block,
-            interpret=not _on_tpu(),
+            interpret=interpret,
         )
     if q.shape[1] > 2 * q_block:
         return layers.attn_chunked(q, k, v, causal=causal,

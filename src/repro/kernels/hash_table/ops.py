@@ -1,9 +1,8 @@
 """Jit'd dispatch for the hash-table kernels.
 
-``use_pallas`` selects the Pallas kernel (interpret=True on CPU — the TPU
-path drops interpret); the default (None) picks Pallas only on TPU backends
-so CPU tests, benchmarks and the dry-run use the XLA reference path while
-kernel tests exercise the Pallas path explicitly.
+``use_pallas`` selects the Pallas kernel (default: the XLA reference
+path). ``interpret`` runs that kernel in the Pallas interpreter; only a
+caller without a TPU asks for it (the kernel tests on the CPU).
 
 Also enforces the VMEM-residency sizing rule from kernel.py: a table that
 exceeds the budget is not rejected — it is dispatched through the sharded
@@ -26,49 +25,48 @@ from repro.kernels.hash_table import kernel, ref
 VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def table_bytes(tkeys, tvals) -> int:
+    """VMEM bytes the kernels hold for this table (tile padding included;
+    kernel.vmem_bytes)."""
     nb, s, vw = tvals.shape
-    return nb * s * (3 + vw) * 4
+    return kernel.vmem_bytes(nb, s, vw)
 
 
 def _n_shards(tkeys, tvals) -> int:
-    nb = tkeys.shape[0]
-    return ws.shards_for_budget(
-        table_bytes(tkeys, tvals), VMEM_BUDGET_BYTES, nb
-    )
+    """Fewest power-of-two bucket shards whose packed slice fits the
+    budget (a shard's tiles pad on their own, so count per shard)."""
+    nb, s, vw = tvals.shape
+    m = 1
+    while (kernel.vmem_bytes(nb // m, s, vw) > VMEM_BUDGET_BYTES
+           and m < nb):
+        m *= 2
+    return m
 
 
-def lookup(tkeys, tvers, tvals, queries, *, use_pallas: bool | None = None):
+def lookup(tkeys, tvers, tvals, queries, *, use_pallas: bool = False,
+           interpret: bool = False):
     """(found, versions, values) for a batch of paired-hash queries."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     if use_pallas:
         m = _n_shards(tkeys, tvals)
         if m > 1:
-            return _sharded_lookup(tkeys, tvers, tvals, queries, m)
+            return _sharded_lookup_scan(tkeys, tvers, tvals, queries, m,
+                                        interpret)
         return kernel.lookup(
-            tkeys, tvers, tvals, queries, interpret=not _on_tpu()
+            tkeys, tvers, tvals, queries, interpret=interpret
         )
     return ref.lookup_ref(tkeys, tvers, tvals, queries)
 
 
 def commit(tkeys, tvers, tvals, wkeys, wvals, active,
-           *, use_pallas: bool | None = None):
+           *, use_pallas: bool = False, interpret: bool = False):
     """Sequential insert-or-update commit. Returns (keys, vers, vals, ovf)."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     if use_pallas:
         m = _n_shards(tkeys, tvals)
         if m > 1:
-            return _sharded_commit(tkeys, tvers, tvals, wkeys, wvals,
-                                   active, m)
+            return _sharded_commit_scan(tkeys, tvers, tvals, wkeys, wvals,
+                                        active, m, interpret)
         return kernel.commit(
-            tkeys, tvers, tvals, wkeys, wvals, active,
-            interpret=not _on_tpu(),
+            tkeys, tvers, tvals, wkeys, wvals, active, interpret=interpret
         )
     return ref.commit_ref(tkeys, tvers, tvals, wkeys, wvals, active)
 
@@ -131,12 +129,6 @@ def _sharded_lookup_scan(tkeys, tvers, tvals, queries, n_shards: int,
     return found, vers, vals
 
 
-def _sharded_lookup(tkeys, tvers, tvals, queries, n_shards: int):
-    return _sharded_lookup_scan(
-        tkeys, tvers, tvals, queries, n_shards, not _on_tpu()
-    )
-
-
 @functools.partial(jax.jit, static_argnames=("n_shards", "interpret"))
 def _sharded_commit_scan(tkeys, tvers, tvals, wkeys, wvals, active,
                          n_shards: int, interpret: bool):
@@ -157,12 +149,6 @@ def _sharded_commit_scan(tkeys, tvers, tvals, wkeys, wvals, active,
     )
     okeys, overs, ovals = ws.merge_table(ks, vs, vls)
     return okeys, overs, ovals, ovf
-
-
-def _sharded_commit(tkeys, tvers, tvals, wkeys, wvals, active, n_shards: int):
-    return _sharded_commit_scan(
-        tkeys, tvers, tvals, wkeys, wvals, active, n_shards, not _on_tpu()
-    )
 
 
 @functools.partial(jax.jit, static_argnames=("n_shards",))

@@ -1,20 +1,14 @@
-"""Jit'd dispatch for the endorsement-MAC kernel (Pallas on TPU, ref on CPU)."""
+"""Dispatch for the endorsement-MAC kernel: the Pallas kernel when the
+caller asks for it (``interpret`` only without a TPU), else the reference."""
 
 from __future__ import annotations
-
-import jax
 
 from repro.kernels.sig_mac import kernel, ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def mac_many(msg, rs, ss, *, use_pallas: bool | None = None):
+def mac_many(msg, rs, ss, *, use_pallas: bool = False,
+             interpret: bool = False):
     """(B, W) messages x (NE,) keys -> (B, NE) tags."""
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     if use_pallas:
-        return kernel.mac_many(msg, rs, ss, interpret=not _on_tpu())
+        return kernel.mac_many(msg, rs, ss, interpret=interpret)
     return ref.mac_many_ref(msg, rs, ss)

@@ -33,6 +33,10 @@ from repro.launch import sharding, specs  # noqa: E402
 from repro.models.lm import LM  # noqa: E402
 from repro.training import optimizer, train_step as ts_lib  # noqa: E402
 
+# The chip the production meshes model (16 GB of HBM per device); records
+# carry it so benchmarks/roofline.py reads that chip's peaks.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 
@@ -210,7 +214,7 @@ def run_cell(arch: str, shape: shp.ShapeSpec, mesh_name: str,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
-            cost = _cost_dict(compiled)
+            cost = compiled.cost_analysis()
             hlo_text = compiled.as_text()
             coll = parse_collectives(hlo_text)
             tc_cost = hlo_cost.analyze(hlo_text)  # trip-count-corrected
@@ -219,6 +223,7 @@ def run_cell(arch: str, shape: shp.ShapeSpec, mesh_name: str,
             **meta,
             "mesh": mesh_name,
             "n_devices": mesh.size,
+            "device_kind": TARGET_DEVICE_KIND,
             "status": "ok",
             "lower_s": round(t_lower, 1),
             "compile_s": round(t_compile, 1),
@@ -250,10 +255,6 @@ def run_cell(arch: str, shape: shp.ShapeSpec, mesh_name: str,
         }
     _write(path, rec)
     return rec
-
-
-def _cost_dict(compiled) -> dict:
-    return hlo_cost.cost_dict(compiled)
 
 
 def _save_hlo(json_path: str, hlo_text: str) -> None:
@@ -317,7 +318,7 @@ def run_fabric_cell(variant: str, mesh_name: str, out_dir: str,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             mem = compiled.memory_analysis()
-            cost = _cost_dict(compiled)
+            cost = compiled.cost_analysis()
             hlo_text = compiled.as_text()
             coll = parse_collectives(hlo_text)
             tc_cost = hlo_cost.analyze(hlo_text)
@@ -326,6 +327,7 @@ def run_fabric_cell(variant: str, mesh_name: str, out_dir: str,
         rec = {
             "arch": variant, "shape": "step", "step": "fabric",
             "mesh": mesh_name, "n_devices": mesh.size, "status": "ok",
+            "device_kind": TARGET_DEVICE_KIND,
             "txs_per_round": txs, "payload_bytes": dims.payload_bytes,
             "lower_s": round(t_lower, 1), "compile_s": round(t_compile, 1),
             "memory": {

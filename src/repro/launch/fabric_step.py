@@ -45,15 +45,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6: public API, replication check kwarg is `check_vma`
-    _shard_map = jax.shard_map
-    _SHARD_MAP_NO_CHECK = {"check_vma": False}
-except AttributeError:  # jax 0.4.x/0.5.x: experimental, kwarg is `check_rep`
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_NO_CHECK = {"check_rep": False}
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import orderer, types, unmarshal
 from repro.core import world_state as ws
@@ -85,19 +77,32 @@ class FabricMeshState(NamedTuple):
 
 
 def create_mesh_state(n_channels: int, dims: types.FabricDims,
-                      n_buckets: int = 1 << 10, slots: int = 8
-                      ) -> FabricMeshState:
+                      n_buckets: int = 1 << 10, slots: int = 8, *,
+                      mesh=None, shard_state: bool = False,
+                      channels_over_data: bool = True) -> FabricMeshState:
+    """An empty channel group. With ``mesh`` every array is created in
+    place with its :func:`state_specs` sharding (a sharded table is never
+    whole on one device); without, on the default device."""
     z = lambda *s: jnp.zeros(s, U32)
-    return FabricMeshState(
-        keys=z(n_channels, n_buckets, slots, 2),
-        versions=z(n_channels, n_buckets, slots),
-        values=z(n_channels, n_buckets, slots, dims.vw),
-        log_head=z(n_channels, 2),
-        ledger_head=z(n_channels, 2),
-        journal_head=z(n_channels, 2),
-        block_no=z(n_channels),
-        overflow=z(n_channels, state_sharding.OVERFLOW_LANES),
-    )
+
+    def build():
+        return FabricMeshState(
+            keys=z(n_channels, n_buckets, slots, 2),
+            versions=z(n_channels, n_buckets, slots),
+            values=z(n_channels, n_buckets, slots, dims.vw),
+            log_head=z(n_channels, 2),
+            ledger_head=z(n_channels, 2),
+            journal_head=z(n_channels, 2),
+            block_no=z(n_channels),
+            overflow=z(n_channels, state_sharding.OVERFLOW_LANES),
+        )
+
+    if mesh is None:
+        return build()
+    specs = state_specs(mesh, shard_state=shard_state,
+                        channels_over_data=channels_over_data)
+    return jax.jit(build, out_shardings=FabricMeshState(
+        *(NamedSharding(mesh, s) for s in specs)))()
 
 
 def state_specs(mesh, *, shard_state: bool = False,
@@ -239,7 +244,7 @@ def make_fabric_step(dims: types.FabricDims, cfg: "FabricStepConfig", mesh,
                         channels_over_data=channels_over_data)
     cd = "data" if channels_over_data else None
     io_spec = P(cd, "model", None)
-    step = _shard_map(
+    step = jax.shard_map(
         step_local,
         mesh=mesh,
         in_specs=(cspec.keys, cspec.versions, cspec.values,
@@ -248,7 +253,7 @@ def make_fabric_step(dims: types.FabricDims, cfg: "FabricStepConfig", mesh,
         out_specs=(cspec.keys, cspec.versions, cspec.values, cspec.log_head,
                    cspec.ledger_head, cspec.journal_head, cspec.block_no,
                    cspec.overflow, P(cd, "model")),
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
 
     def apply(state: FabricMeshState, wire, ids):
@@ -282,7 +287,7 @@ def _make_pipelined(dims: types.FabricDims, cfg: "FabricStepConfig", mesh,
                         channels_over_data=channels_over_data)
     cd = "data" if channels_over_data else None
     io_spec = P(cd, None, "model", None)  # (C, D, B_round, ...)
-    step = _shard_map(
+    step = jax.shard_map(
         step_local,
         mesh=mesh,
         in_specs=(cspec.keys, cspec.versions, cspec.values,
@@ -291,7 +296,7 @@ def _make_pipelined(dims: types.FabricDims, cfg: "FabricStepConfig", mesh,
         out_specs=(cspec.keys, cspec.versions, cspec.values, cspec.log_head,
                    cspec.ledger_head, cspec.journal_head, cspec.block_no,
                    cspec.overflow, P(cd, None, "model")),
-        **_SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
 
     def apply(state: FabricMeshState, wire, ids):

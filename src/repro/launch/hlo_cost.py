@@ -331,15 +331,6 @@ class HloModule:
         return self.comp_cost(entry)
 
 
-def cost_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions (newer
-    versions return a dict, older ones a one-element list of dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def analyze(hlo_text: str) -> dict:
     mod = HloModule(hlo_text)
     c = mod.entry_cost()

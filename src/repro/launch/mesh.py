@@ -1,4 +1,4 @@
-"""Production meshes.
+"""Meshes, all built by :func:`make_mesh`.
 
 Single pod: 16 x 16 = 256 chips, axes (data, model).
 Multi-pod:  2 x 16 x 16 = 512 chips, axes (pod, data, model) — the pod axis
@@ -13,19 +13,25 @@ jax device state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes=("data", "model"), *, devices=None):
+    """The one mesh constructor of the repo. Every axis is
+    ``AxisType.Auto``: the step programs rely on sharding propagation, and
+    JAX >= 0.9 makes ``jax.make_mesh`` axes Explicit unless told otherwise
+    (explicit axes put ``data`` into the step outputs' types, which then
+    no longer vmap with host-built arrays). ``devices`` defaults to the
+    first ``prod(shape)`` of ``jax.devices()``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_host_mesh():
-    """Whatever this host has, as a 1 x N (data, model) mesh — used by the
-    CPU examples and tests."""
-    n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+    return make_mesh(shape, axes)
 
 
 def dp_axes(mesh) -> tuple:

@@ -41,6 +41,7 @@ from repro.core import ledger, types
 from repro.core import world_state as ws
 from repro.launch import fabric_step as fs
 from repro.launch import state_sharding
+from repro.launch.mesh import make_mesh
 
 U32 = jnp.uint32
 
@@ -140,12 +141,12 @@ def make_resize_program(cfg: fs.FabricStepConfig, mesh, old_nb: int,
         # False) — on a 1-rank data axis this is the old spec exactly.
         spec = fs.state_specs(mesh, shard_state=True,
                               channels_over_data=False)
-        return fs._shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(spec.keys, spec.versions, spec.values),
             out_specs=(spec.keys, spec.versions, spec.values,
                        spec.overflow),
-            **fs._SHARD_MAP_NO_CHECK,
+            check_vma=False,
         )
 
     def prog_fn(keys, vers, vals):
@@ -212,7 +213,7 @@ class MeshWindowCommitter:
                  mesh=None, *, n_buckets: int = 1 << 12, slots: int = 8,
                  n_channels: int = 1):
         if mesh is None:
-            mesh = jax.make_mesh((1, 1), ("data", "model"))
+            mesh = make_mesh((1, 1))
         if n_channels < 1:
             raise ValueError(f"n_channels must be >= 1, got {n_channels}")
         self.dims = dims
@@ -224,7 +225,9 @@ class MeshWindowCommitter:
             _ChannelGroup(
                 tuple(range(n_channels)),
                 fs.create_mesh_state(
-                    n_channels, dims, n_buckets=n_buckets, slots=slots
+                    n_channels, dims, n_buckets=n_buckets, slots=slots,
+                    mesh=mesh, shard_state=cfg.shard_state,
+                    channels_over_data=n_channels % mesh.shape["data"] == 0,
                 ),
             )
         ]
@@ -454,10 +457,7 @@ class MeshWindowCommitter:
         wire_g, ids_g = wires[chans], tx_ids[chans]
         args = ((group.state, wire_g[:, 0], ids_g[:, 0]) if d == 1
                 else (group.state, wire_g, ids_g))
-        try:
-            an = hlo_cost.analyze(jstep.lower(*args).compile().as_text())
-        except Exception:
-            return  # cost model is best-effort; never fail a commit
+        an = hlo_cost.analyze(jstep.lower(*args).compile().as_text())
         reg = self.obs.registry
         reg.gauge("hlo.collectives", depth=d).set(
             sum(v["count"] for v in an["collectives"].values())

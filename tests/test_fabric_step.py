@@ -10,13 +10,14 @@ import pytest
 from repro.core import endorser, engine, types, unmarshal
 from repro.core import world_state as ws
 from repro.launch import fabric_step as fs
+from repro.launch.mesh import make_mesh
 
 DIMS = types.TEST_DIMS
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1))
 
 
 def _round(n=32, seed=0):

@@ -35,6 +35,7 @@ import pytest
 from repro.core import endorser, engine, ledger, types, unmarshal
 from repro.launch import fabric_step as fs
 from repro.pipeline import engine_bridge
+from repro.launch.mesh import make_mesh
 
 DIMS = types.TEST_DIMS
 N_DEV = len(jax.devices())
@@ -79,7 +80,7 @@ def _multichannel_vs_oracles(shard_state, depth, data, model):
     """Live: C=2 channels lockstep, channel 1 resizes 128->256 after two
     windows. Oracles: each channel's exact per-channel history replayed
     on a single-channel committer. Everything must match, per channel."""
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = make_mesh((data, model))
     cfg = fs.FabricStepConfig(shard_state=shard_state, pipeline_depth=depth)
     streams = [_windows(4, depth, seed=5), _windows(4, depth, seed=77)]
 
@@ -146,7 +147,7 @@ def test_multichannel_equals_oracles_sharded_data_ranks():
 def test_multichannel_four_channels_two_data_ranks():
     """4 channels over 2 data ranks (2 local channels per rank): the
     vmap-inside-shard_map layout, no resize — quick layout pin."""
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2))
     cfg = fs.FabricStepConfig(shard_state=True, pipeline_depth=2)
     streams = [_windows(2, 2, seed=11 * (c + 1)) for c in range(4)]
     live = engine_bridge.MeshWindowCommitter(
@@ -172,7 +173,7 @@ def test_multichannel_four_channels_two_data_ranks():
 
 
 def test_engine_multichannel_meshed_rounds_verify_all(tmp_path):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wc = engine_bridge.MeshWindowCommitter(
         DIMS, fs.FabricStepConfig(pipeline_depth=2), mesh,
         n_buckets=256, slots=8, n_channels=2)

@@ -23,6 +23,7 @@ import pytest
 from repro.core import endorser, engine, types, unmarshal
 from repro.launch import fabric_step as fs
 from repro.pipeline import engine_bridge
+from repro.launch.mesh import make_mesh
 
 DIMS = types.TEST_DIMS
 N_DEV = len(jax.devices())
@@ -94,12 +95,42 @@ def _assert_identical(cfg, mesh, wire, ids, depth, n_buckets=256, slots=8):
     return v2, st2
 
 
+# ------------------------------------------------------- mesh axis types
+
+
+def test_mesh_axes_auto_chain_hashes_over_step_output():
+    """The one mesh constructor makes every axis Auto, so a step output
+    (sharded over the mesh) and host-built arrays vmap together in the
+    store-chain hash. JAX >= 0.9 defaults ``jax.make_mesh`` axes to
+    Explicit, which made this raise "inconsistent axis specs: None vs
+    data"; a change of that default, or of the constructor, fails here."""
+    mesh = make_mesh((1, 1))
+    assert set(mesh.axis_types) == {jax.sharding.AxisType.Auto}
+    depth = 2
+    wire, ids = _window(depth, n=16, seed=3)
+    step = jax.jit(fs.make_fabric_step(
+        DIMS, fs.FabricStepConfig(pipeline_depth=depth), mesh),
+        donate_argnums=(0,))
+    st = fs.create_mesh_state(1, DIMS, n_buckets=256, mesh=mesh)
+    st, valid = step(st, wire[None], ids[None])
+    bno0 = st.block_no - jnp.uint32(depth)  # output of the step, on mesh
+    prev = jnp.stack([jnp.zeros((2,), jnp.uint32)])  # built on the host
+    prevs, hashes = engine_bridge._chain_hashes_multi(
+        prev, bno0, wire[None], valid)
+    want_prevs, want_hashes = engine_bridge._chain_hashes(
+        jnp.zeros((2,), jnp.uint32), jnp.uint32(0), wire, valid[0])
+    np.testing.assert_array_equal(np.asarray(prevs[0]),
+                                  np.asarray(want_prevs))
+    np.testing.assert_array_equal(np.asarray(hashes[0]),
+                                  np.asarray(want_hashes))
+
+
 # ------------------------------------------------------- oracle equivalence
 
 
 @pytest.mark.parametrize("depth", [2, 8])
 def test_pipelined_equals_oracle_replicated(depth):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wire, ids = _window(depth, n=16, seed=depth)
     v, st = _assert_identical(fs.FASTFABRIC_STEP, mesh, wire, ids, depth)
     assert int(v.sum()) == v.size  # disjoint accounts: all valid
@@ -107,7 +138,7 @@ def test_pipelined_equals_oracle_replicated(depth):
 
 
 def test_pipelined_equals_oracle_sharded_degenerate():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wire, ids = _window(2, n=16, seed=9)
     _assert_identical(fs.FASTFABRIC_SHARDED_STEP, mesh, wire, ids, 2)
 
@@ -117,7 +148,7 @@ def test_pipelined_equals_oracle_sharded_degenerate():
 def test_pipelined_equals_oracle_sharded_multi_rank(depth):
     """Acceptance: depth-D window on >=2 model ranks with sharded state is
     byte-identical to the depth-1 oracle — one routed gather per window."""
-    mesh = jax.make_mesh((1, min(MAX_M, 4)), ("data", "model"))
+    mesh = make_mesh((1, min(MAX_M, 4)))
     wire, ids = _window(depth, n=32, seed=depth)
     _assert_identical(fs.FASTFABRIC_SHARDED_STEP, mesh, wire, ids, depth)
 
@@ -125,7 +156,7 @@ def test_pipelined_equals_oracle_sharded_multi_rank(depth):
 def test_pipelined_equals_oracle_baseline_config():
     """The serial fabric-1.2 folds (non-pipelined consensus, sequential
     commit) pipeline too: the schedule reuses the exact per-block math."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wire, ids = _window(2, n=16, seed=5)
     _assert_identical(fs.FABRIC_V12_STEP, mesh, wire, ids, 2)
 
@@ -138,7 +169,7 @@ def test_cross_block_read_your_write_commit_order(depth):
     """Block k reads keys block k-1 wrote (expecting the bumped version):
     every transaction is valid ONLY if commits apply in block order and
     the batched fill-time gather is repaired with in-window writes."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wire, ids = _window(depth, n=16, seed=1, read_your_write=True)
     v, _ = _assert_identical(fs.FASTFABRIC_STEP, mesh, wire, ids, depth)
     assert int(v.sum()) == v.size  # stale fill-time versions would zero
@@ -147,7 +178,7 @@ def test_cross_block_read_your_write_commit_order(depth):
 
 @multi_device
 def test_cross_block_read_your_write_sharded_multi_rank():
-    mesh = jax.make_mesh((1, min(MAX_M, 4)), ("data", "model"))
+    mesh = make_mesh((1, min(MAX_M, 4)))
     wire, ids = _window(4, n=32, seed=2, read_your_write=True)
     v, _ = _assert_identical(fs.FASTFABRIC_SHARDED_STEP, mesh, wire, ids, 4)
     assert int(v.sum()) == v.size
@@ -156,7 +187,7 @@ def test_cross_block_read_your_write_sharded_multi_rank():
 def test_replayed_window_invalidated():
     """Replaying the same window leaves every version stale (the pipeline
     does not leak fill-time versions into the second window)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wire, ids = _window(2, n=16, seed=7)
     st = fs.create_mesh_state(1, DIMS, n_buckets=256)
     step = jax.jit(fs.make_fabric_step(
@@ -185,7 +216,7 @@ def test_overflow_window_equals_oracle_replicated(depth):
     """Acceptance: overflowing windows stay byte-identical to the depth-1
     oracle (the old window write log counted dropped inserts as version
     bumps, so any in-window read of a dropped key diverged)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wire, ids = _overflow_window(depth)
     v, st = _assert_identical(fs.FASTFABRIC_STEP, mesh, wire, ids, depth,
                               n_buckets=8, slots=2)
@@ -197,7 +228,7 @@ def test_overflow_window_equals_oracle_replicated(depth):
 
 @pytest.mark.parametrize("depth", [2, 4])
 def test_overflow_window_equals_oracle_sharded_degenerate(depth):
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wire, ids = _overflow_window(depth)
     _, st = _assert_identical(fs.FASTFABRIC_SHARDED_STEP, mesh, wire, ids,
                               depth, n_buckets=8, slots=2)
@@ -213,7 +244,7 @@ def test_overflow_window_equals_oracle_sharded_multi_rank(depth):
     including the per-shard overflow BITMASK (bit m == shard m filled),
     which the depth-1 routed commit and the pipelined planner must agree
     on without an extra collective."""
-    mesh = jax.make_mesh((1, min(MAX_M, 4)), ("data", "model"))
+    mesh = make_mesh((1, min(MAX_M, 4)))
     wire, ids = _overflow_window(depth, n=16)
     _, st = _assert_identical(fs.FASTFABRIC_SHARDED_STEP, mesh, wire, ids,
                               depth, n_buckets=8, slots=2)
@@ -223,7 +254,7 @@ def test_overflow_window_equals_oracle_sharded_multi_rank(depth):
 def test_overflow_window_equals_oracle_sequential_baseline():
     """The sequential-commit baseline bumps every duplicate occurrence and
     fills slots in write order; the planner must mirror that flavor too."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wire, ids = _overflow_window(4)
     _, st = _assert_identical(fs.FABRIC_V12_STEP, mesh, wire, ids, 4,
                               n_buckets=8, slots=2)
@@ -299,7 +330,7 @@ def test_engine_overflow_reports_unhealthy(tmp_path):
 
 
 def test_pipelined_rejects_wrong_window_shape():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1))
     wire, ids = _window(2, n=16)
     step = fs.make_fabric_step(
         DIMS, dataclasses.replace(fs.FASTFABRIC_STEP, pipeline_depth=4),

@@ -38,6 +38,7 @@ from repro.launch import state_sharding
 from repro.pipeline import engine_bridge
 from repro.storage import journal as journal_mod
 from repro.storage import recovery, snapshot
+from repro.launch.mesh import make_mesh
 
 DIMS = types.TEST_DIMS
 N_DEV = len(jax.devices())
@@ -185,7 +186,7 @@ def test_resize_property_partition_bijection_and_lookups():
 def _mesh_resize(full, m, new_nb_loc, nb_glob):
     from jax.sharding import PartitionSpec as P
 
-    mesh = jax.make_mesh((1, m), ("data", "model"))
+    mesh = make_mesh((1, m))
 
     def body(keys, vers, vals):
         local = ws.HashState(keys, vers, vals)
@@ -193,11 +194,11 @@ def _mesh_resize(full, m, new_nb_loc, nb_glob):
         return (res.state.keys, res.state.versions, res.state.values,
                 res.shard_overflow.astype(jnp.uint32)[None])
 
-    prog = fs._shard_map(
+    prog = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P("model"), P("model"), P("model")),
         out_specs=(P("model"), P("model"), P("model"), P("model")),
-        **fs._SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
     k, v, va, ovf = jax.jit(prog)(full.keys, full.versions, full.values)
     return ws.HashState(np.asarray(k), np.asarray(v), np.asarray(va)), ovf
@@ -264,7 +265,7 @@ def _windows(n_windows, depth, n=16, seed=0):
 def _split_mid_run(shard_state, depth, m):
     """Live: 2 windows at 128 buckets, split to 256, 2 windows. Oracle:
     all 4 windows on 256 from block 0. Everything must match."""
-    mesh = jax.make_mesh((1, m), ("data", "model"))
+    mesh = make_mesh((1, m))
     cfg = fs.FabricStepConfig(shard_state=shard_state, pipeline_depth=depth)
     wins = _windows(4, depth, seed=5)
     live = engine_bridge.MeshWindowCommitter(
