@@ -122,8 +122,7 @@ def percentile_cols(hist, prefix: str = "commit") -> dict:
     }
 
 
-def metrics_cols(collected: dict, name: str = "commit.latency",
-                 prefix: str = "commit") -> dict:
+def metrics_cols(collected: dict, name: str, prefix: str) -> dict:
     """Absorb one histogram out of a ``Registry.collect()`` snapshot into
     row columns (p50/p95/p99 in ms + count). Empty when the metric is
     absent (obs off or the path never recorded)."""

@@ -130,7 +130,7 @@ def _run_depth(dims, mesh, label: str, cfg, depth: int, b_round: int,
     t = float(np.median(samples))
     # Per-block commit latency percentiles: a window's blocks retire
     # together, so each iteration contributes its amortized wall/D once
-    # per block — the same accounting the engine's commit.latency uses.
+    # per block.
     lat = common.latency_hist(
         [s / depth for s in samples for _ in range(depth)])
     total = sum(colls.values())
@@ -189,11 +189,10 @@ def _obs_overhead(dims, mesh, cfg, depth: int, b_round: int,
                   obs_dir: str | None = None) -> None:
     """Instrumentation cost at the deepest pipeline: the SAME window
     committed through MeshWindowCommitter with obs detached vs attached
-    (window spans + commit.latency + counters on the hot path; the HLO
-    cost gauges record during warmup, outside the timed loop). The
-    acceptance bar is <= 2% TPS — spans sync only at edges the un-instru-
-    mented path already syncs (commit_window materializes the chain
-    hashes), so the delta is null-call + histogram-bucket arithmetic.
+    (window spans + counters on the hot path). The acceptance bar is
+    <= 2% TPS — spans sync only at edges the uninstrumented path already
+    syncs (commit_window materializes the chain hashes), so the delta is
+    null calls and counter arithmetic.
 
     With ``obs_dir`` the obs-on run dumps trace.jsonl, trace_chrome.json
     and metrics.json there (the CI smoke artifact)."""
@@ -217,8 +216,7 @@ def _obs_overhead(dims, mesh, cfg, depth: int, b_round: int,
 
         # warmup=2: the first call compiles for the freshly created
         # (unsharded) state, the second for the step's mesh-sharded output
-        # layout; steady state starts at the third. The obs-on warmup also
-        # absorbs the one-time HLO cost-gauge lowering.
+        # layout; steady state starts at the third.
         samples[mode] = common.timed_samples(
             run_once, warmup=2, iters=max(iters, 9))
         tps[mode] = depth * b_round / float(np.median(samples[mode]))
@@ -260,11 +258,11 @@ def _obs_overhead(dims, mesh, cfg, depth: int, b_round: int,
         with open(os.path.join(obs_dir, "metrics.json"), "w") as f:
             json.dump(m, f, indent=1)
         # The CI smoke contract: the trace holds steady-phase spans and
-        # the registry a populated commit-latency histogram.
+        # the registry counted the committed windows.
         steady = [r for r in on.tracer.records()
                   if r["name"] == "window.steady"]
         assert len(steady) >= 1, "no window.steady span in the obs trace"
-        assert m["commit.latency"]["count"] > 0, "commit.latency is empty"
+        assert m["window.commits"] > 0, "window.commits is empty"
 
 
 def _txtrace_overhead(dims, mesh, cfg, depth: int, b_round: int,

@@ -58,7 +58,7 @@ def _mk_engine(policy, n_buckets, slots, block_size, *, n_shards=1,
         snapshot_every_blocks=snapshot_every,
         snapshot_dir=snapshot_dir,
         journal_dir=journal_dir,
-        obs=True,  # per-engine registry: commit latency + resize events
+        obs=True,  # per-engine registry: resize events and counters
         # Health verdicts here contract on overflow/occupancy, not wall
         # clock: a compile-noise-proof latency objective keeps the
         # static-critical / elastic-healthy contrast deterministic.
@@ -113,7 +113,6 @@ def run(rounds: int, round_txs: int, n_buckets: int, slots: int,
                 health_reason=(v.reasons[0] if v.reasons else ""),
                 resize_grows=m.get("resize.grow", 0),
                 overflow_latches=m.get("overflow.latches", 0),
-                **common.metrics_cols(m),
             )
 
         # Equivalence: whole workload replayed on the FINAL layout == the

@@ -1,10 +1,9 @@
 """Program-contract static analysis for the hot-path programs.
 
 Performance invariants used to be enforced ad hoc (a ``commit_scatters``
-assert in fig11, ``hlo.*`` gauges in the window committer); this package
-turns each of them into a *declarative, committed contract* checked
-against the compiled artifact of every registered hot-path program —
-without running a workload:
+assert in fig11); this package turns each of them into a *declarative,
+committed contract* checked against the compiled artifact of every
+registered hot-path program — without running a workload:
 
   * :mod:`repro.analysis.registry`  — program registry: each jitted hot
     path (the fabric step per (depth, shards, channels), the stacked

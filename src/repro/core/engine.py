@@ -105,8 +105,8 @@ class EngineConfig:
     resize_policy: ResizePolicy | None = None
     snapshot_shards: int = 1
     # Observability (repro/obs): True builds a per-engine tracer + metrics
-    # registry and instruments the round path (per-stage spans, the
-    # commit.latency histogram, tx/overflow/journal counters, resize
+    # registry and instruments the round path (per-stage spans, also on
+    # the jax.profiler clock; tx/overflow/journal counters, resize
     # events, per-tx lifecycle tracing). False routes every probe to the
     # shared no-op sinks — the hot path gains only null calls, no device
     # syncs. An obs.Obs instance is also accepted (benchmarks sharing one
@@ -282,7 +282,8 @@ class FabricEngine:
             if cfg.block_dir is not None:
                 os.makedirs(cfg.block_dir, exist_ok=True)
             self.store = ledger.BlockStore(
-                cfg.block_dir, journal=self.chans[0].journal
+                cfg.block_dir, journal=self.chans[0].journal,
+                tracer=self.obs.tracer,
             )
             for c in range(1, cfg.n_channels):
                 if self.chans[c].journal is not None:
@@ -453,14 +454,15 @@ class FabricEngine:
         if n % bs:
             raise ValueError(f"round of {n} txs not a multiple of {bs}")
 
+        tracer = self.obs.tracer
         # Endorse (endorser cluster; separate hardware under P-II). The
         # replica must reflect all previously retired blocks first.
-        txb = endorser.endorse_jit(
-            ch.endorser_state, proposals, cfg.dims,
-            n_endorsers=cfg.n_endorsers,
-        )
-        wire = jax.block_until_ready(unmarshal.marshal(txb, cfg.dims))
-        tracer, reg = self.obs.tracer, self.obs.registry
+        with tracer.span("round.endorse", channel=channel):
+            txb = endorser.endorse_jit(
+                ch.endorser_state, proposals, cfg.dims,
+                n_endorsers=cfg.n_endorsers,
+            )
+            wire = jax.block_until_ready(unmarshal.marshal(txb, cfg.dims))
         # Tx-lifecycle sidecar: tx-ids assigned at submission (the wire
         # is ready — the endorser's content hashes ARE the ids). The
         # sidecar transfer is the obs-on cost; obs-off passes None.
@@ -523,14 +525,6 @@ class FabricEngine:
 
                 jax.block_until_ready(ch.peer_state.ledger_head)
                 txr.validated(0, n_blocks)
-            # Per-block commit latency: blocks stay in flight async (the
-            # paper's block shepherds), so individual block walls don't
-            # exist — amortize the round's order+commit wall over its
-            # blocks (the window path amortizes per window the same way).
-            dt = (time.perf_counter() - t0) / n_blocks
-            hist = reg.histogram("commit.latency")
-            for _ in range(n_blocks):
-                hist.record(dt)
         wall = time.perf_counter() - t0
 
         # Post-window: endorser-cluster replica updates (their hardware).
@@ -553,7 +547,7 @@ class FabricEngine:
         all channels' windows in lockstep — one ``commit_windows`` device
         dispatch per window position covers every channel."""
         cfg = self.cfg
-        tracer, reg = self.obs.tracer, self.obs.registry
+        tracer = self.obs.tracer
         wires, blocks_by_ch = [], []
         for c, proposals in enumerate(proposals_by_channel):
             ch = self.chans[c]
@@ -563,13 +557,14 @@ class FabricEngine:
                     f"channel {c}: round of {n} txs not a multiple of "
                     f"{cfg.orderer.block_size}"
                 )
-            txb = endorser.endorse_jit(
-                ch.endorser_state, proposals, cfg.dims,
-                n_endorsers=cfg.n_endorsers,
-            )
-            wires.append(
-                jax.block_until_ready(unmarshal.marshal(txb, cfg.dims))
-            )
+            with tracer.span("round.endorse", channel=c):
+                txb = endorser.endorse_jit(
+                    ch.endorser_state, proposals, cfg.dims,
+                    n_endorsers=cfg.n_endorsers,
+                )
+                wires.append(
+                    jax.block_until_ready(unmarshal.marshal(txb, cfg.dims))
+                )
             blocks_by_ch.append((txb, wires[-1]))
         shapes = {w.shape for w in wires}
         if len(shapes) > 1:
@@ -1129,7 +1124,8 @@ class FabricEngine:
                 # the fresh store multiplexes every restored journal.
                 self.store.close()
                 store = ledger.BlockStore(cfg.block_dir,
-                                          journal=self.chans[0].journal)
+                                          journal=self.chans[0].journal,
+                                          tracer=self.obs.tracer)
                 for c2 in range(1, cfg.n_channels):
                     if self.chans[c2].journal is not None:
                         store.set_journal(c2, self.chans[c2].journal)

@@ -18,6 +18,7 @@ import functools
 import os
 import queue
 import threading
+import time
 from typing import NamedTuple
 
 import jax
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import hashing, types, unmarshal, world_state
+from repro.obs.trace import NULL_TRACER
 
 U32 = jnp.uint32
 
@@ -142,10 +144,16 @@ class BlockStore:
     channel i's chain or journal fails only channel i's checks). The
     channel-0 surface (``.chain``, ``.base_block_no``, ``.base_hash``,
     channel-less method calls) is the pre-multi-channel API unchanged.
+
+    With a live ``tracer`` the writer records one ``store.append`` span per
+    block: from the dequeue through the host copy, the spill and the
+    journal to the chain append, with ``queued_s`` (submit to dequeue).
     """
 
-    def __init__(self, spill_dir: str | None = None, *, journal=None):
+    def __init__(self, spill_dir: str | None = None, *, journal=None,
+                 tracer=NULL_TRACER):
         self._q: "queue.Queue" = queue.Queue()
+        self._tracer = tracer
         self.chains: dict[int, list[StoredBlock]] = {0: []}
         self.base_block_nos: dict[int, int] = {0: -1}
         self.base_hashes: dict[int, np.ndarray] = {
@@ -225,7 +233,8 @@ class BlockStore:
         self._chan(channel)  # channel registered caller-side: the writer
         # thread then only appends to an existing list (no dict mutation
         # races between submit and the drain thread).
-        self._q.put((block_no, prev_hash, block_hash, wire, valid, channel))
+        self._q.put((block_no, prev_hash, block_hash, wire, valid, channel,
+                     time.perf_counter()))
 
     def _run(self) -> None:
         while True:
@@ -244,18 +253,22 @@ class BlockStore:
                 continue
             spill_path = None
             try:
-                channel = item[-1]
-                bno, prev, bh, wire, valid = jax.device_get(item[:-1])
-                sb = StoredBlock(int(bno), prev, bh, wire, valid)
-                if self._spill_dir is not None:
-                    spill_path = self._spill_path(channel, int(bno))
-                    np.savez(
-                        spill_path,
-                        prev_hash=prev, block_hash=bh, wire=wire, valid=valid,
-                    )
-                jrnl = self._journals.get(channel)
-                if jrnl is not None:
-                    jrnl.append_block(int(bno), wire, valid)
+                channel, t_submit = item[-2:]
+                with self._tracer.span(
+                    "store.append", block_no=int(item[0]), channel=channel,
+                    queued_s=time.perf_counter() - t_submit,
+                ):
+                    bno, prev, bh, wire, valid = jax.device_get(item[:-2])
+                    sb = StoredBlock(int(bno), prev, bh, wire, valid)
+                    if self._spill_dir is not None:
+                        spill_path = self._spill_path(channel, int(bno))
+                        np.savez(
+                            spill_path, prev_hash=prev, block_hash=bh,
+                            wire=wire, valid=valid,
+                        )
+                    jrnl = self._journals.get(channel)
+                    if jrnl is not None:
+                        jrnl.append_block(int(bno), wire, valid)
                 # Chain append last: a block is in the chain only if every
                 # sink (spill, journal) accepted it, so the sinks can never
                 # silently trail the chain.

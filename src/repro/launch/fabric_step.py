@@ -256,7 +256,7 @@ def make_fabric_step(dims: types.FabricDims, cfg: "FabricStepConfig", mesh,
         check_vma=False,
     )
 
-    def apply(state: FabricMeshState, wire, ids):
+    def window_step(state: FabricMeshState, wire, ids):
         if cfg.shard_state:
             ws.shard_buckets(state.keys.shape[1], msize)  # validate split
         out = step(
@@ -266,7 +266,7 @@ def make_fabric_step(dims: types.FabricDims, cfg: "FabricStepConfig", mesh,
         )
         return FabricMeshState(*out[:-1]), out[-1]
 
-    return apply
+    return window_step
 
 
 def _make_pipelined(dims: types.FabricDims, cfg: "FabricStepConfig", mesh,
@@ -299,7 +299,7 @@ def _make_pipelined(dims: types.FabricDims, cfg: "FabricStepConfig", mesh,
         check_vma=False,
     )
 
-    def apply(state: FabricMeshState, wire, ids):
+    def window_step(state: FabricMeshState, wire, ids):
         if cfg.shard_state:
             ws.shard_buckets(state.keys.shape[1], msize)  # validate split
         if wire.ndim != 4 or wire.shape[1] != depth:
@@ -314,7 +314,7 @@ def _make_pipelined(dims: types.FabricDims, cfg: "FabricStepConfig", mesh,
         )
         return FabricMeshState(*out[:-1]), out[-1]
 
-    return apply
+    return window_step
 
 
 @dataclasses.dataclass(frozen=True)

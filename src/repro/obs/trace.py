@@ -39,9 +39,14 @@ long soak runs stop growing the event list without losing the recent
 window that matters for a post-mortem. The default stays unbounded (short
 benchmark runs export their complete trace).
 
-Stdlib-only module: ``jax`` is imported lazily inside ``_block`` so the
-obs package itself stays dependency-free (and so does every unit test of
-the tracer).
+A live span is also a ``jax.profiler.TraceAnnotation`` of the same name,
+opened on entry and closed after the exit sync: in a ``jax.profiler``
+trace the span sits on the host thread that opened it, on the same clock
+as the device ops, so an idle gap of the device can be named by the
+engine layer the host was in. ``NullTracer`` opens none.
+
+``jax`` is imported lazily (in ``_block`` and ``Span.__enter__``), so the
+obs package imports without it and the null tracer never touches it.
 """
 
 from __future__ import annotations
@@ -105,7 +110,8 @@ def _block(obj) -> None:
 class Span:
     """Context manager for one timed region. Created via Tracer.span."""
 
-    __slots__ = ("tracer", "name", "sync", "args", "t0", "depth", "parent")
+    __slots__ = ("tracer", "name", "sync", "args", "t0", "depth", "parent",
+                 "annotation")
 
     def __init__(self, tracer: "Tracer", name: str, sync, args: dict):
         self.tracer = tracer
@@ -115,12 +121,17 @@ class Span:
         self.t0 = 0.0
         self.depth = 0
         self.parent = None
+        self.annotation = None
 
     def __enter__(self) -> "Span":
         stack = self.tracer._stack()
         self.depth = len(stack)
         self.parent = stack[-1].name if stack else None
         stack.append(self)
+        from jax import profiler
+
+        self.annotation = profiler.TraceAnnotation(self.name)
+        self.annotation.__enter__()
         # Sync on entry too, so pending work dispatched *before* the span
         # is not billed to it. Entry sync reuses the same target: by the
         # time the span opens the target usually doesn't exist yet, so
@@ -133,8 +144,11 @@ class Span:
         self.sync = sync
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        if exc_type is None:
-            _block(self.sync)
+        try:
+            if exc_type is None:
+                _block(self.sync)
+        finally:
+            self.annotation.__exit__(None, None, None)
         t1 = time.perf_counter()
         stack = self.tracer._stack()
         if stack and stack[-1] is self:

@@ -29,7 +29,6 @@ and runs its usual durability checks against the per-channel
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import NamedTuple
 
 import jax
@@ -237,7 +236,6 @@ class MeshWindowCommitter:
         self._resizes: dict = {}
         self._stats: dict = {}
         self.obs = obs_mod.Obs.disabled()
-        self._hlo_gauged: set[int] = set()
         self._auditor = None
 
     def attach_retrace_auditor(self, auditor) -> None:
@@ -382,12 +380,8 @@ class MeshWindowCommitter:
             )
         d = wires.shape[1]
         tracer, reg = self.obs.tracer, self.obs.registry
-        t0 = time.perf_counter()
         step_by_group = [self._step_for(d, g.channels)
                          for g in self.groups]
-        if self.obs.on and d not in self._hlo_gauged:
-            self._record_hlo_gauges(step_by_group[0], self.groups[0],
-                                    d, wires, tx_ids)
         valid_by_channel: list = [None] * self.n_channels
         prevs_by_channel: list = [None] * self.n_channels
         hashes_by_channel: list = [None] * self.n_channels
@@ -428,12 +422,6 @@ class MeshWindowCommitter:
             # role's input). This is the sync the obs-off path pays too.
             prevs = np.stack([np.asarray(p) for p in prevs_by_channel])
             hashes = np.stack([np.asarray(h) for h in hashes_by_channel])
-        # Per-block commit latency, amortized over the window (blocks
-        # inside a window retire together — the fused commit is the point).
-        dt = (time.perf_counter() - t0) / d
-        hist = reg.histogram("commit.latency")
-        for _ in range(d):
-            hist.record(dt)
         reg.counter("window.commits").inc()
         reg.counter("blocks.committed").inc(d * self.n_channels)
         if self.n_channels > 1:
@@ -443,29 +431,6 @@ class MeshWindowCommitter:
             valid=jnp.stack(valid_by_channel), prev_hash=prevs,
             block_hash=hashes,
         )
-
-    def _record_hlo_gauges(self, jstep, group, d: int, wires, tx_ids
-                           ) -> None:
-        """Fold the compiled window program's cost model into gauges
-        (launch/hlo_cost): collective count, wire bytes, scatter count —
-        the contract numbers fig11 asserts, now visible per depth on any
-        obs-enabled run. One-time per depth (AOT-lowers the same jit)."""
-        from repro.launch import hlo_cost
-
-        self._hlo_gauged.add(d)
-        chans = jnp.asarray(list(group.channels))
-        wire_g, ids_g = wires[chans], tx_ids[chans]
-        args = ((group.state, wire_g[:, 0], ids_g[:, 0]) if d == 1
-                else (group.state, wire_g, ids_g))
-        an = hlo_cost.analyze(jstep.lower(*args).compile().as_text())
-        reg = self.obs.registry
-        reg.gauge("hlo.collectives", depth=d).set(
-            sum(v["count"] for v in an["collectives"].values())
-        )
-        reg.gauge("hlo.collective_wire_bytes", depth=d).set(
-            an["collective_wire_bytes"]
-        )
-        reg.gauge("hlo.scatter_count", depth=d).set(an["scatter_count"])
 
     # -- elastic state: resize epochs --------------------------------------
 

@@ -205,6 +205,136 @@ def test_null_tracer_never_syncs():
     assert NULL_TRACER.records() == []
 
 
+def _host_events(trace_dir) -> list:
+    """``(start_ns, dur_ns, name, line)`` of every host event in the one
+    ``.xplane.pb`` that ``jax.profiler`` wrote under ``trace_dir``."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path, = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    return [(e.start_ns, e.duration_ns, e.name, i)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for i, line in enumerate(plane.lines) for e in line.events]
+
+
+def test_live_span_is_a_profiler_host_event(tmp_path):
+    """A live span is written into a real ``jax.profiler`` trace under its
+    own name, nested on the same thread inside the annotation that
+    encloses it, and covers its exit sync; the null tracer writes none."""
+    import jax
+    import jax.numpy as jnp
+
+    tr = Tracer()
+    x = jnp.arange(4096.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test.outer"):
+            with tr.span("test.live", sync=lambda: y):
+                y = jnp.sin(x) * 2
+            with NULL_TRACER.span("test.null"):
+                jnp.cos(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(tmp_path)
+    (o_s, o_d, _, o_line), = [e for e in ev if e[2] == "test.outer"]
+    (s_s, s_d, _, s_line), = [e for e in ev if e[2] == "test.live"]
+    assert s_line == o_line
+    assert o_s <= s_s and s_s + s_d <= o_s + o_d
+    assert not [e for e in ev if e[2] == "test.null"]
+    rec, = tr.records()
+    assert rec["name"] == "test.live"
+    # the annotation closes after the sync, so it spans the tracer's record
+    assert s_d >= 0.5 * rec["dur"] * 1e9
+
+
+def test_live_span_annotation_closes_when_the_body_raises(tmp_path):
+    """A span whose body raises still closes its profiler annotation: the
+    event is in the trace, and the next annotation on the thread is not
+    nested inside it."""
+    import jax
+
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with pytest.raises(ValueError):
+            with tr.span("test.raises"):
+                raise ValueError("body")
+        with jax.profiler.TraceAnnotation("test.after"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    ev = _host_events(tmp_path)
+    (r_s, r_d, _, r_line), = [e for e in ev if e[2] == "test.raises"]
+    (a_s, _, _, a_line), = [e for e in ev if e[2] == "test.after"]
+    assert a_line == r_line
+    assert a_s >= r_s + r_d
+    assert tr._stack() == []
+
+
+def test_engine_round_spans_are_profiler_host_events(tmp_path):
+    """With obs on, one engine round writes its layer spans into a
+    ``jax.profiler`` trace inside the caller's annotation, on the caller's
+    thread; the store writer's ``store.append`` spans sit on another."""
+    import jax
+
+    from repro.core import engine as eng_mod
+    from repro.core import types
+
+    eng = eng_mod.FabricEngine(eng_mod.EngineConfig(
+        dims=types.TEST_DIMS, n_buckets=1 << 12, obs=True))
+    bs = eng.cfg.orderer.block_size
+    props = eng.make_proposals(2 * bs, seed=3)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation("test.round"):
+            eng.run_round(props)
+        eng.store.drain()
+    finally:
+        jax.profiler.stop_trace()
+    eng.store.close()
+    ev = _host_events(tmp_path)
+    (o_s, o_d, _, o_line), = [e for e in ev if e[2] == "test.round"]
+    for name in ("round.endorse", "round.order", "round.commit",
+                 "round.endorser_replay"):
+        hits = [e for e in ev if e[2] == name]
+        assert hits, name
+        assert all(line == o_line and o_s <= s and s + d <= o_s + o_d
+                   for s, d, _, line in hits), name
+    appends = [e for e in ev if e[2] == "store.append"]
+    assert len(appends) == 2
+    assert {line for *_, line in appends} != {o_line}
+
+
+def test_block_store_traces_each_append():
+    """``BlockStore(tracer=...)`` records one ``store.append`` per block on
+    its writer thread, with the block's number, its channel and the time
+    it waited in the queue."""
+    import threading
+
+    from repro.core import ledger
+
+    tr = Tracer()
+    store = ledger.BlockStore(tracer=tr)
+    blocks = [(b, c) for b in range(3) for c in (0, 2)]
+    for bno, c in blocks:
+        store.submit(bno, np.zeros(2, np.uint32),
+                     np.full(2, bno + 1, np.uint32),
+                     np.zeros((4, 8), np.uint32), np.ones(4, bool),
+                     channel=c)
+    store.drain()
+    store.close()
+    recs = tr.records()
+    assert [r["name"] for r in recs] == ["store.append"] * len(blocks)
+    assert sorted((r["args"]["block_no"], r["args"]["channel"])
+                  for r in recs) == sorted(blocks)
+    assert all(r["args"]["queued_s"] >= 0 and r["dur"] >= 0 for r in recs)
+    assert {r["tid"] for r in recs} == {store._t.ident}
+    assert store._t.ident != threading.get_ident()
+    assert [len(store.chains[c]) for c in (0, 2)] == [3, 3]
+
+
 def test_obs_handle():
     off = Obs.disabled()
     assert not off.on
@@ -297,25 +427,25 @@ def test_engine_metrics_stable_across_restore(tmp_path):
     eng = eng_mod.FabricEngine(cfg)
     bs = cfg.orderer.block_size
     eng.run_round(eng.make_proposals(4 * bs, seed=1))
+    eng.store.drain()
     m = eng.metrics()
     assert m["journal.appends"] == 4
-    assert m["commit.latency"]["count"] == 4
     assert m["txs.valid"] == 4 * bs
     assert m["snapshot.saves"] == 1
     names = {r["name"] for r in eng.tracer.records()}
-    assert {"round.order", "round.commit", "round.endorser_replay",
-            "block.ship", "snapshot.take"} <= names
-    eng.store.drain()
+    assert {"round.endorse", "round.order", "round.commit",
+            "round.endorser_replay", "block.ship", "store.append",
+            "snapshot.take"} <= names
     eng.store.close()
 
     eng2 = eng_mod.FabricEngine.restore(cfg)
     m2 = eng2.metrics()
     assert m2.get("journal.appends", 0) == 0  # reload is not an append
-    assert "commit.latency" not in m2
+    assert "txs.valid" not in m2
     eng2.run_round(eng2.make_proposals(2 * bs, seed=2))
     m3 = eng2.metrics()
     assert m3["journal.appends"] == 2
-    assert m3["commit.latency"]["count"] == 2
+    assert m3["txs.valid"] == 2 * bs
     assert all(eng2.verify().values())
 
 
