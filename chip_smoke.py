@@ -183,11 +183,10 @@ def endorsed_windows(n_windows: int, seed: int, n_buckets: int):
                                    n_accounts=N_ACCOUNTS)
         txb = endorser.endorse_jit(eng.endorser_state, props, DIMS,
                                    n_endorsers=eng.cfg.n_endorsers)
-        wire = unmarshal.marshal(txb, DIMS)
-        out.append((wire.reshape(DEPTH, bs, -1),
-                    txb.tx_id.reshape(DEPTH, bs, 2)))
-        eng.endorser_state = endorser.apply_validated_jit(
-            eng.endorser_state, txb, jnp.ones(DEPTH * bs, bool))
+        wire = unmarshal.marshal(txb, DIMS).reshape(DEPTH, bs, -1)
+        out.append((wire, txb.tx_id.reshape(DEPTH, bs, 2)))
+        eng.endorser_state, _ = endorser.replay_blocks_jit(
+            eng.endorser_state, wire, jnp.ones((DEPTH, bs), bool), DIMS)
     return out
 
 

@@ -18,7 +18,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import crypto, hashing, types
+from repro.core import crypto, hashing, types, unmarshal
 from repro.core import world_state as ws
 
 U32 = jnp.uint32
@@ -113,7 +113,31 @@ def apply_validated(
     ).state
 
 
+def replay_blocks(
+    state: ws.HashState, wires: jnp.ndarray, valids: jnp.ndarray,
+    dims: types.FabricDims,
+) -> tuple[ws.HashState, jnp.ndarray]:
+    """Endorser-cluster replica update for a run of retired blocks in one
+    program: ``wires`` (n_blocks, B, 4*payload_words) u8 and ``valids``
+    (n_blocks, B) bool. Each block's write set, decoded from its wire,
+    applies in block order, so a key rewritten by a later block ends as
+    that block left it. Returns the new replica and the count of valid
+    transactions (a device scalar)."""
+
+    # A static loop, not a scan: XLA then keeps the table in fast memory
+    # across the blocks, where a scan body moves it there and back per
+    # block.
+    for b in range(wires.shape[0]):
+        txb = unmarshal.unmarshal(wires[b], dims).txb
+        state = apply_validated(state, txb, valids[b])
+    return state, valids.sum(dtype=jnp.int32)
+
+
 endorse_jit = jax.jit(
     execute_and_endorse, static_argnames=("dims", "n_endorsers")
 )
-apply_validated_jit = jax.jit(apply_validated)
+# The replica is donated: the update writes the table in place instead of
+# copying it (the wires and valids stay live for the block store).
+replay_blocks_jit = jax.jit(
+    replay_blocks, static_argnames=("dims",), donate_argnums=(0,)
+)

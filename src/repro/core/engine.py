@@ -391,9 +391,11 @@ class FabricEngine:
         Timing boundary follows the paper's §IV-D measurement: the client
         sends *pre-endorsed* transactions, so endorsement/marshaling is
         client/endorser-cluster work outside the peer-throughput window;
-        the endorser-replica updates after validation run on the endorser
-        cluster's hardware (P-II role separation) and are applied after
-        the timed window here (block handoff itself is async).
+        the endorser-replica update after validation runs on the endorser
+        cluster's hardware (P-II role separation) and is applied after
+        ``RoundStats.wall_s`` ends here (block handoff itself is async).
+        It is still inside the round, so a client that times its commits
+        end to end pays for it.
 
         A multi-channel engine backed by a mesh window committer commits
         all channels per dispatch — drive it with :meth:`run_rounds`;
@@ -503,7 +505,7 @@ class FabricEngine:
                              channel=channel,
                              sync=lambda: ch.peer_state.ledger_head):
                 in_flight = []
-                retired = []
+                retired = []  # per-block validity bits, for the replay
                 for b in range(n_blocks):
                     bno = ch.next_block_no
                     ch.next_block_no += 1
@@ -516,12 +518,11 @@ class FabricEngine:
                     ch.overflow = ch.overflow | res.overflow
                     in_flight.append((blocks.wire[b], bno, prev_head,
                                       res.block_hash, res.valid))
+                    retired.append(res.valid)
                     if len(in_flight) >= max(cfg.peer.pipeline_depth, 1):
-                        retired.append(
-                            self._ship(*in_flight.pop(0), channel=channel))
+                        self._ship(*in_flight.pop(0), channel=channel)
                 while in_flight:
-                    retired.append(
-                        self._ship(*in_flight.pop(0), channel=channel))
+                    self._ship(*in_flight.pop(0), channel=channel)
 
                 jax.block_until_ready(ch.peer_state.ledger_head)
                 txr.validated(0, n_blocks)
@@ -529,7 +530,7 @@ class FabricEngine:
 
         # Post-window: endorser-cluster replica updates (their hardware).
         n_valid, valids = self._endorser_replay(
-            retired, channel, collect_valid=self.obs.on
+            blocks.wire, retired, channel, collect_valid=self.obs.on
         )
         txr.committed()
         self._policy_pass((channel,))
@@ -613,21 +614,22 @@ class FabricEngine:
                     txr.validated(lo, hi)
                 for c in range(cfg.n_channels):
                     ch = self.chans[c]
+                    retired[c].append(res.valid[c])
                     for k in range(hi - lo):
                         bno = ch.next_block_no
                         ch.next_block_no += 1
-                        retired[c].append(self._ship(
+                        self._ship(
                             ordered[c].wire[lo + k], bno,
                             res.prev_hash[c, k], res.block_hash[c, k],
                             res.valid[c, k], channel=c,
-                        ))
+                        )
             wc.block_until_ready()
         wall = time.perf_counter() - t0
 
         replayed = []
         for c in range(cfg.n_channels):
             n_valid, valids = self._endorser_replay(
-                retired[c], c, collect_valid=self.obs.on
+                ordered[c].wire, retired[c], c, collect_valid=self.obs.on
             )
             txrs[c].committed()
             replayed.append((n_valid, valids))
@@ -647,32 +649,34 @@ class FabricEngine:
             ))
         return stats
 
-    def _endorser_replay(self, retired: list, channel: int,
+    def _endorser_replay(self, wires, retired: list, channel: int,
                          collect_valid: bool = False) -> tuple:
-        """Endorser-cluster replica updates (their hardware) for one
-        channel's retired blocks; returns ``(n_valid, valid_by_block)``.
-        ``valid_by_block`` is one host-side bool array per block when
-        ``collect_valid`` (the tx-outcome feed), else None — the obs-off
-        path keeps its scalar-only host transfers."""
+        """Endorser-cluster replica update (their hardware) for one
+        channel's round: ``wires`` (n_blocks, B, W) are the round's
+        blocks, ``retired`` their validity bits, per block (B,) or per
+        window (k, B). One program applies every block in order; returns
+        ``(n_valid, valid_by_block)``. ``valid_by_block`` is one host-side
+        bool array per block when ``collect_valid`` (the tx-outcome feed),
+        else None — the obs-off path keeps its scalar-only host transfer."""
         ch = self.chans[channel]
-        n_valid = 0
-        valids: list | None = [] if collect_valid else None
         with self.obs.tracer.span(
             "round.endorser_replay", channel=channel,
             sync=lambda: ch.endorser_state.versions,
         ):
-            for wire_b, valid in retired:
-                dec = unmarshal.unmarshal(wire_b, self.cfg.dims)
-                ch.endorser_state = endorser.apply_validated_jit(
-                    ch.endorser_state, dec.txb, valid
-                )
-                if collect_valid:
-                    v = np.asarray(valid)
-                    valids.append(v)
-                    n_valid += int(v.sum())
-                else:
-                    n_valid += int(valid.sum())
-        return n_valid, valids
+            if retired[0].ndim == 1:  # the host path's per-block bits
+                valids = jnp.stack(retired)
+            else:  # one window returns its own bits, with no copy
+                valids = jnp.concatenate(retired)
+            ch.endorser_state, n_valid = endorser.replay_blocks_jit(
+                ch.endorser_state, wires, valids, self.cfg.dims
+            )
+            reg = self.obs.registry
+            reg.counter("replay.dispatches").inc()
+            if self.cfg.n_channels > 1:
+                reg.counter("replay.dispatches", channel=channel).inc()
+            by_block = list(np.asarray(valids)) if collect_valid else None
+            n_valid = int(n_valid)
+        return n_valid, by_block
 
     def _count_round(self, channel: int, n: int, n_valid: int,
                      wall_s: float, n_blocks: int) -> int:
@@ -711,7 +715,8 @@ class FabricEngine:
         """Slice the ordered round into pipeline-depth windows and hand
         each to the window committer; ship every block to the store with
         the committer's chain hashes. A round tail shorter than the depth
-        becomes one shallower window (compiled once, reused)."""
+        becomes one shallower window (compiled once, reused). Returns each
+        window's (k, B) validity bits."""
         wc = self.window_committer
         ch = self.chans[channel]
         retired = []
@@ -723,13 +728,14 @@ class FabricEngine:
                 # commit_window host-synced the window's chain hashes in
                 # its drain span — blocks [lo, hi) validated on that edge.
                 txr.validated(lo, hi)
+            retired.append(res.valid)
             for k in range(hi - lo):
                 bno = ch.next_block_no
                 ch.next_block_no += 1
-                retired.append(self._ship(
+                self._ship(
                     blocks.wire[lo + k], bno, res.prev_hash[k],
                     res.block_hash[k], res.valid[k], channel=channel,
-                ))
+                )
         return retired
 
     def _ship(self, wire_b, bno: int, prev_head, block_hash, valid,
@@ -740,7 +746,6 @@ class FabricEngine:
                                       channel=channel):
                 self.store.submit(bno, prev_head, block_hash, wire_b,
                                   valid, channel=channel)
-        return wire_b, valid
 
     # -- observability ---------------------------------------------------------
 

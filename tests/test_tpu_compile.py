@@ -21,7 +21,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import committer, engine, types
+from repro.core import committer, endorser, engine, types
+from repro.core import world_state as ws
 from repro.kernels.hash_table import kernel as htk
 from repro.kernels.hash_table import ops as ht_ops
 from repro.kernels.mvcc_validate import kernel as mvk
@@ -91,6 +92,21 @@ def test_host_commit_block_compiles(one_chip):
     compiled = committer.commit_block_fused.lower(
         state, wire, DIMS, engine.FASTFABRIC.peer).compile()
     _fits_chip(compiled)
+
+
+def test_endorser_replay_compiles(one_chip):
+    """The endorser replica's round replay (8 blocks) with the replica
+    donated: the whole table is aliased, so the update is in place."""
+    state = _on(one_chip, jax.eval_shape(
+        lambda: ws.create(N_BUCKETS, 8, DIMS.vw)))
+    wires = jax.ShapeDtypeStruct((8, BLOCK, 4 * DIMS.payload_words),
+                                 jnp.uint8, sharding=one_chip)
+    valids = jax.ShapeDtypeStruct((8, BLOCK), jnp.bool_, sharding=one_chip)
+    compiled = endorser.replay_blocks_jit.lower(
+        state, wires, valids, DIMS).compile()
+    _fits_chip(compiled)
+    table_bytes = sum(a.size * a.dtype.itemsize for a in state)
+    assert compiled.memory_analysis().alias_size_in_bytes == table_bytes
 
 
 def _kernel_args(name, s):
